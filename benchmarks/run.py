@@ -50,6 +50,8 @@ if os.environ.get("REPRO_FORCE_DEVICES"):
 
 
 def main(argv=None) -> None:
+    from repro import compile_cache
+    compile_cache.enable()
     from benchmarks import bench_algos, bench_bitadj, bench_ewise, \
         bench_khop, bench_kernels, bench_ktruss, bench_mutations, \
         bench_throughput, bench_triangles

@@ -8,11 +8,12 @@ frontiers ride the bitmap-packed path automatically).
 """
 import numpy as np
 
-from repro import algorithms as alg
+from repro import algorithms as alg, compile_cache
 from repro.core import grb
 from repro.graph.datagen import rmat_edges
 from repro.graph.graph import GraphBuilder
 
+compile_cache.enable()
 src, dst, n = rmat_edges(scale=10, edge_factor=8, seed=1)
 keep = src != dst
 src, dst = src[keep], dst[keep]

@@ -6,7 +6,10 @@ over a device mesh (`mesh=`, PR 4's sharded surface).
 """
 import tempfile
 
+from repro import compile_cache
 from repro.engine import Database
+
+compile_cache.enable()
 
 db = Database(data_dir=tempfile.mkdtemp(prefix="repro_aof_"))
 

@@ -10,11 +10,13 @@ workload.
 """
 import argparse
 import os
+import sys
 import tempfile
 import time
 
 import numpy as np
 
+from repro import compile_cache
 from repro.engine import QueryServer, load_snapshot, save_snapshot
 from repro.graph.datagen import rmat_graph
 
@@ -25,6 +27,7 @@ ap.add_argument("--k", type=int, default=2)
 ap.add_argument("--rate", type=float, default=2000.0,
                 help="offered Poisson arrival rate, queries/sec")
 args = ap.parse_args()
+compile_cache.enable()
 
 print(f"[1/4] generating Graph500 RMAT scale={args.scale} ...")
 g = rmat_graph(scale=args.scale, edge_factor=8, seed=0, fmt="bsr", block=128)
@@ -61,6 +64,11 @@ while len(out) < args.queries:
 dt = time.perf_counter() - t0
 
 print("[4/4] results:")
+errors = [(q, out[q].error) for q in qids if out[q].error is not None]
+if errors:
+    for q, err in errors[:10]:
+        print(f"      query {q} failed: {err}", file=sys.stderr)
+    sys.exit(f"{len(errors)} of {len(qids)} queries failed")
 counts = [out[q].scalar() for q in qids]
 lat = np.array([m.latency_s for m in srv.log])
 p50, p99 = np.percentile(lat, [50, 99])

@@ -7,12 +7,14 @@ The same launcher drives the 16x16-mesh dry-run configs.
 import argparse
 import tempfile
 
+from repro import compile_cache
 from repro.launch.train import main as train_main
 
 ap = argparse.ArgumentParser()
 ap.add_argument("--arch", default="qwen2-1.5b")
 ap.add_argument("--steps", type=int, default=30)
 args = ap.parse_args()
+compile_cache.enable()
 
 ckpt_dir = tempfile.mkdtemp(prefix="repro_ckpt_")
 losses = train_main(["--arch", args.arch, "--steps", str(args.steps),

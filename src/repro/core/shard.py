@@ -112,10 +112,10 @@ class ShardedELL:
         msk[:n] = np.asarray(e.mask)
         val[:n] = np.asarray(e.values)
         sh = NamedSharding(mesh, P(ROW_AXIS, None))
-        return cls((n, m), mesh,
-                   jax.device_put(jnp.asarray(idx), sh),
-                   jax.device_put(jnp.asarray(msk), sh),
-                   jax.device_put(jnp.asarray(val), sh), nnz=e.nnz)
+        # host arrays straight to their shards: no whole copy on one device
+        return cls((n, m), mesh, jax.device_put(idx, sh),
+                   jax.device_put(msk, sh), jax.device_put(val, sh),
+                   nnz=e.nnz)
 
     @classmethod
     def from_coo(cls, rows, cols, vals, shape, mesh: Mesh) -> "ShardedELL":

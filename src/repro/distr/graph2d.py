@@ -59,19 +59,20 @@ from repro.core.ell import ELL
 from repro.core.shard import frontier_axes as _frontier_axes
 from repro.core.shard import frontier_spec as _fr_spec
 
-# shard_map moved from jax.experimental to jax core (and its replication-check
-# kwarg was renamed check_rep -> check_vma); resolve whichever this jax ships.
-try:
-    _shard_map = jax.shard_map
-    _SM_CHECK_KW = "check_vma"
-except AttributeError:
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _SM_CHECK_KW = "check_rep"
-
-
 def _smap(body, mesh, in_specs, out_specs):
-    return _shard_map(body, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **{_SM_CHECK_KW: False})
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
+
+
+def _ell_words(local: ELL, xw):
+    """Shard-local packed gather-reduce: on a TPU the one-device route's
+    Pallas kernel (`kernels.bitmap_mxv`), whose working set is the edges;
+    elsewhere the XLA reference, whose (rows, deg, W) gather would not fit
+    a chip at deployment widths."""
+    if jax.default_backend() == "tpu":
+        from repro.kernels import bitmap_mxv     # lazy: kernels import core
+        return bitmap_mxv.ell_mxv_packed(local, xw)
+    return _core_ops.ell_mxm_packed(local, xw)
 
 
 def ell_shard_inputs(A, sentinel: bool = False):
@@ -144,7 +145,7 @@ def mxm_2d(mesh: Mesh, sr: S.Semiring, transposed: bool = False,
             xw = jax.lax.all_gather(xw_l, "data", axis=0, tiled=True)
             local = ELL(shape=(idx_l.shape[0], xw.shape[0]), indices=idx_l,
                         mask=msk_l, values=val_l, nnz=0)
-            return _core_ops.ell_mxm_packed(local, xw)
+            return _ell_words(local, xw)
     elif not transposed:
         def body(idx_l, msk_l, val_l, x_l):
             x = jax.lax.all_gather(x_l, "data", axis=0, tiled=True)
@@ -476,11 +477,7 @@ def scan_host_transfers(fn, *args, **kwargs):
     ``grb.host_transfers()`` regression (the counter pins the Python-level
     gathers the tracer can't see)."""
     lowered = jax.jit(fn).lower(*args, **kwargs)
-    texts = [lowered.as_text()]
-    try:
-        texts.append(lowered.compile().as_text())
-    except Exception:            # pragma: no cover - backend-dependent
-        pass
+    texts = [lowered.as_text(), lowered.compile().as_text()]
     hits = []
     for txt in texts:
         for ln in txt.splitlines():

@@ -334,6 +334,13 @@ class Database:
     def query(self, name: str, text: str, impl: str = "auto",
               mesh=None) -> Result:
         q = parse(text)
+        if (isinstance(q, (A.CreateQuery, A.DeleteQuery))
+                and "external" in self._graph(name)._builds):
+            # a bulk-loaded graph is served as loaded: a write would be
+            # acknowledged and then never read back
+            raise TypeError(f"graph {name!r} was bulk-loaded (load_graph) "
+                            f"and takes no writes; build it through CREATE "
+                            f"to serve writes on the delta path")
         if isinstance(q, A.CreateQuery):
             self._append_aof(name, text)
             return self._apply_create(name, q)
@@ -370,7 +377,8 @@ class Database:
         return explain(self._graph(name).freeze(), text)
 
     def load_graph(self, name: str, graph_or_builder) -> None:
-        """Bulk load a pre-built Graph (datagen path)."""
+        """Bulk load a pre-built Graph (datagen path). It is served as
+        loaded and read-only: CREATE / DELETE on it raise TypeError."""
         mg = self._graph(name)
         g = graph_or_builder
         mg._builds = {"external": g}

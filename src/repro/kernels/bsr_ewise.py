@@ -43,10 +43,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams across releases
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    getattr(pltpu, "TPUCompilerParams")
-
 EWISE_MODES = ("union", "intersect", "apply", "select", "mask", "mask_c")
 
 # modes whose second operand is never read (the B gather is a dummy)
@@ -121,7 +117,8 @@ def _ewise_pallas(Ab, Bb, sel_a, pa, sel_b, pb, *, mode: str, op,
         ),
         out_shape=jax.ShapeDtypeStruct((nt, b, b), jnp.float32),
         interpret=interpret,
-        compiler_params=_CompilerParams(dimension_semantics=("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
     )(sel_a, pa, sel_b, pb, Ab, Bb)
 
 

@@ -13,7 +13,8 @@ Layout / schedule
                Pallas only writes it back to HBM when brow changes.
 
 Scalar prefetch (pltpu.PrefetchScalarGridSpec) feeds the tile coordinate
-arrays (block_rows / block_cols / first / last / valid) to the index maps —
+arrays (block_rows / block_cols, and first / last / valid packed into one
+flags word) to the index maps and the body —
 the sparsity pattern steers DMA, the kernel body stays dense (MXU).
 
 Semiring specialization
@@ -43,23 +44,23 @@ from repro.core.bsr import BSR
 
 DEFAULT_F_TILE = 128
 
-# jax renamed TPUCompilerParams -> CompilerParams across releases; resolve
-# whichever this jax ships so the kernel builds on both sides of the rename.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    getattr(pltpu, "TPUCompilerParams")
+# per-tile schedule flags, packed into one scalar-prefetch word per tile so
+# the prefetch holds 3 int32 per tile (SMEM is 1 MiB)
+FIRST, LAST, VALID = 1, 2, 4
 
 
-def _kernel(brow_ref, bcol_ref, first_ref, last_ref, valid_ref,  # scalar prefetch
+def _kernel(brow_ref, bcol_ref, flags_ref,                     # scalar prefetch
             blocks_ref, x_ref, mask_ref, y_ref, *,
             sr: S.Semiring, masked: bool, complement: bool, bcast_chunk: int):
     k = pl.program_id(1)
     ident = np.float32(sr.identity)
+    flags = flags_ref[k]
 
-    @pl.when(first_ref[k] == 1)
+    @pl.when((flags & FIRST) != 0)
     def _init():
         y_ref[...] = jnp.full_like(y_ref, ident)
 
-    @pl.when(valid_ref[k] == 1)
+    @pl.when((flags & VALID) != 0)
     def _accum():
         a = blocks_ref[0].astype(jnp.float32)          # (bm, bk)
         x = x_ref[...].astype(jnp.float32)             # (bk, ft)
@@ -80,14 +81,15 @@ def _kernel(brow_ref, bcol_ref, first_ref, last_ref, valid_ref,  # scalar prefet
             y_ref[...] = y_ref[...] + part
         elif sr.mode == "bcast":
             # tropical inner block: chunk rows of A to bound the (rows, bk, ft)
-            # broadcast intermediate inside VMEM.
-            a_s = jnp.where(a != 0, a, ident)
-            bm = a_s.shape[0]
-            nchunk = bm // bcast_chunk
+            # broadcast intermediate inside VMEM; the chunk is sliced from
+            # the ref (Mosaic has no dynamic_slice on a loaded value)
+            nchunk = blocks_ref.shape[1] // bcast_chunk
 
             def body(i, _):
-                rows = jax.lax.dynamic_slice_in_dim(
-                    a_s, i * bcast_chunk, bcast_chunk)               # (ch, bk)
+                r0 = pl.multiple_of(i * bcast_chunk, bcast_chunk)
+                rows = blocks_ref[0, pl.ds(r0, bcast_chunk), :].astype(
+                    jnp.float32)
+                rows = jnp.where(rows != 0, rows, ident)             # (ch, bk)
                 prod = sr.mul(rows[:, :, None], x[None, :, :])       # (ch, bk, ft)
                 part = sr.add.reduce(prod, axis=1)                   # (ch, ft)
                 cur = y_ref[pl.dslice(i * bcast_chunk, bcast_chunk), :]
@@ -99,7 +101,7 @@ def _kernel(brow_ref, bcol_ref, first_ref, last_ref, valid_ref,  # scalar prefet
             raise NotImplementedError(sr.mode)
 
     if masked:
-        @pl.when(last_ref[k] == 1)
+        @pl.when((flags & LAST) != 0)
         def _epilogue():
             m = mask_ref[...]
             keep = (m == 0) if complement else (m != 0)
@@ -133,27 +135,28 @@ def bsr_mxm(A: BSR, X: jnp.ndarray, sr: S.Semiring, *,
     kernel = functools.partial(
         _kernel, sr=sr, masked=mask is not None, complement=complement,
         bcast_chunk=bcast_chunk)
+    flags = (A.first * FIRST + A.last * LAST + A.valid * VALID).astype(
+        jnp.int32)
 
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=5,
+            num_scalar_prefetch=3,
             grid=grid,
             in_specs=[
                 pl.BlockSpec((1, b, b),
-                             lambda fi, k, br, bc, fst, lst, vld: (k, 0, 0)),
+                             lambda fi, k, br, bc, fl: (k, 0, 0)),
                 pl.BlockSpec((b, ft),
-                             lambda fi, k, br, bc, fst, lst, vld: (bc[k], fi)),
+                             lambda fi, k, br, bc, fl: (bc[k], fi)),
                 pl.BlockSpec((b, ft),
-                             lambda fi, k, br, bc, fst, lst, vld: (br[k], fi)),
+                             lambda fi, k, br, bc, fl: (br[k], fi)),
             ],
             out_specs=pl.BlockSpec(
-                (b, ft), lambda fi, k, br, bc, fst, lst, vld: (br[k], fi)),
+                (b, ft), lambda fi, k, br, bc, fl: (br[k], fi)),
         ),
         out_shape=jax.ShapeDtypeStruct((nbr * b, fp), jnp.float32),
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
-    )(A.block_rows, A.block_cols, A.first, A.last, A.valid,
-      A.blocks, Xp, Mp)
+    )(A.block_rows, A.block_cols, flags, A.blocks, Xp, Mp)
     return out[:n, :f]

@@ -13,8 +13,10 @@ Layout / schedule
   C.blocks[c_sel[t]] : (b, b) tile  # is written back once per output tile
   mask_blocks[c_sel[t]]             # mask tile aligned to the output tile
 
-Scalar prefetch feeds (a_sel, b_sel, c_sel, first, last, valid) to the index
-maps: the planned sparsity steers DMA, the body stays a dense (b, b) MXU dot.
+Scalar prefetch feeds (a_sel, b_sel, c_sel) and the first / last / valid
+flags word (`bsr_mxm`'s packing: 4 int32 per task, as SMEM holds 1 MiB) to
+the index maps and the body: the planned sparsity steers DMA, the body
+stays a dense (b, b) MXU dot.
 The GraphBLAS mask is applied in two places: the symbolic phase already
 dropped output tiles outside a non-complemented mask's block pattern, and the
 epilogue on the *last* task of each output tile applies the mask's element
@@ -41,10 +43,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import semiring as S
 from repro.core.bsr import SPGEMM_MODES, SpGEMMPlan
-
-# jax renamed TPUCompilerParams -> CompilerParams across releases
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    getattr(pltpu, "TPUCompilerParams")
+from repro.kernels.bsr_mxm import FIRST, LAST, VALID
 
 
 def _tile_product(a: jnp.ndarray, b: jnp.ndarray, sr: S.Semiring) -> jnp.ndarray:
@@ -61,17 +60,18 @@ def _tile_product(a: jnp.ndarray, b: jnp.ndarray, sr: S.Semiring) -> jnp.ndarray
     raise NotImplementedError(sr.mode)
 
 
-def _kernel(a_sel_ref, b_sel_ref, c_sel_ref, first_ref, last_ref, valid_ref,
+def _kernel(a_sel_ref, b_sel_ref, c_sel_ref, flags_ref,
             ablk_ref, bblk_ref, mblk_ref, y_ref, *,
             sr: S.Semiring, masked: bool, complement: bool):
     t = pl.program_id(0)
     ident = np.float32(sr.identity)
+    flags = flags_ref[t]
 
-    @pl.when(first_ref[t] == 1)
+    @pl.when((flags & FIRST) != 0)
     def _init():
         y_ref[...] = jnp.full_like(y_ref, ident)
 
-    @pl.when(valid_ref[t] == 1)
+    @pl.when((flags & VALID) != 0)
     def _accum():
         a = ablk_ref[0].astype(jnp.float32)
         b = bblk_ref[0].astype(jnp.float32)
@@ -82,7 +82,7 @@ def _kernel(a_sel_ref, b_sel_ref, c_sel_ref, first_ref, last_ref, valid_ref,
             y_ref[0] = y_ref[0] + part
 
     if masked:
-        @pl.when(last_ref[t] == 1)
+        @pl.when((flags & LAST) != 0)
         def _epilogue():
             m = mblk_ref[0]
             keep = (m == 0) if complement else (m != 0)
@@ -92,38 +92,39 @@ def _kernel(a_sel_ref, b_sel_ref, c_sel_ref, first_ref, last_ref, valid_ref,
 @functools.partial(
     jax.jit, static_argnames=("sr", "nc", "block", "masked", "complement",
                               "interpret"))
-def _spgemm_pallas(Ab, Bb, Mb, a_sel, b_sel, c_sel, first, last, valid, *,
+def _spgemm_pallas(Ab, Bb, Mb, a_sel, b_sel, c_sel, flags, *,
                    sr: S.Semiring, nc: int, block: int, masked: bool,
                    complement: bool, interpret: bool) -> jnp.ndarray:
     b = block
     grid = (a_sel.shape[0],)
     kernel = functools.partial(_kernel, sr=sr, masked=masked,
                                complement=complement)
-    mask_map = ((lambda t, asel, bsel, csel, fst, lst, vld: (csel[t], 0, 0))
+    mask_map = ((lambda t, asel, bsel, csel, fl: (csel[t], 0, 0))
                 if masked else
-                (lambda t, asel, bsel, csel, fst, lst, vld: (0, 0, 0)))
+                (lambda t, asel, bsel, csel, fl: (0, 0, 0)))
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=6,
+            num_scalar_prefetch=4,
             grid=grid,
             in_specs=[
                 pl.BlockSpec((1, b, b),
-                             lambda t, asel, bsel, csel, fst, lst, vld:
+                             lambda t, asel, bsel, csel, fl:
                              (asel[t], 0, 0)),
                 pl.BlockSpec((1, b, b),
-                             lambda t, asel, bsel, csel, fst, lst, vld:
+                             lambda t, asel, bsel, csel, fl:
                              (bsel[t], 0, 0)),
                 pl.BlockSpec((1, b, b), mask_map),
             ],
             out_specs=pl.BlockSpec(
                 (1, b, b),
-                lambda t, asel, bsel, csel, fst, lst, vld: (csel[t], 0, 0)),
+                lambda t, asel, bsel, csel, fl: (csel[t], 0, 0)),
         ),
         out_shape=jax.ShapeDtypeStruct((nc, b, b), jnp.float32),
         interpret=interpret,
-        compiler_params=_CompilerParams(dimension_semantics=("arbitrary",)),
-    )(a_sel, b_sel, c_sel, first, last, valid, Ab, Bb, Mb)
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+    )(a_sel, b_sel, c_sel, flags, Ab, Bb, Mb)
 
 
 @functools.partial(
@@ -174,10 +175,11 @@ def spgemm_blocks(Ablocks: jnp.ndarray, Bblocks: jnp.ndarray,
             interpret = jax.default_backend() != "tpu"
         Mb = (mask_blocks if masked
               else jnp.zeros((1, block, block), jnp.float32))
+        flags = (plan.first * FIRST + plan.last * LAST
+                 + plan.valid * VALID).astype(np.int32)
         return _spgemm_pallas(Ablocks, Bblocks, Mb, sel["a_sel"],
-                              sel["b_sel"], sel["c_sel"],
-                              jnp.asarray(plan.first), jnp.asarray(plan.last),
-                              jnp.asarray(plan.valid), sr=sr, nc=plan.nc,
+                              sel["b_sel"], sel["c_sel"], jnp.asarray(flags),
+                              sr=sr, nc=plan.nc,
                               block=block, masked=masked,
                               complement=complement, interpret=interpret)
     # unmasked: the jitted fn never reads Mb (masked is static), so a

@@ -14,19 +14,28 @@ from repro.query.parser import parse
 from repro.query.planner import plan
 
 
+def _edges(store):
+    """(rows, cols) of the nonzero entries, read from sparse storage as
+    COO so the reference never densifies an (n, n) matrix."""
+    if hasattr(store, "to_coo"):
+        rr, cc, vv = store.to_coo()
+        keep = np.asarray(vv) != 0
+        return np.asarray(rr)[keep], np.asarray(cc)[keep]
+    return np.nonzero(np.asarray(store))
+
+
 def _adj(graph: Graph, rel, direction) -> list:
     r = graph.relation(rel)
-    mats = []
+    pairs = []
     if direction in (A.OUT, A.BOTH):
-        mats.append(np.asarray(r.A.to_dense()) != 0)
+        pairs.append(_edges(r.A.store))
     if direction in (A.IN, A.BOTH):
-        mats.append(np.asarray(r.A_T.to_dense()) != 0)
+        pairs.append(_edges(r.A_T.store))
     n = graph.n
     out = [set() for _ in range(n)]
-    for M in mats:
-        rr, cc = np.nonzero(M)
-        for i in range(len(rr)):
-            out[rr[i]].add(int(cc[i]))
+    for rr, cc in pairs:
+        for i, j in zip(rr.tolist(), cc.tolist()):
+            out[i].add(j)
     return out
 
 
@@ -45,6 +54,23 @@ def _bfs_range(adj, seeds: set, minh: int, maxh: int, allowed_dst) -> set:
                 if minh <= lvl[v] <= maxh and allowed_dst[v]:
                     reach.add(v)
     return reach
+
+
+def pagerank_ref(src, dst, n: int, alpha: float = 0.85,
+                 iters: int = 50) -> np.ndarray:
+    """PageRank by float64 power iteration over a unit-weight edge list —
+    the oracle for `algorithms.pagerank` / ``CALL algo.pagerank``, with the
+    same rule for dangling vertices (their mass spreads evenly)."""
+    src = np.asarray(src, dtype=np.int64)
+    dst = np.asarray(dst, dtype=np.int64)
+    deg = np.bincount(src, minlength=n).astype(np.float64)
+    r = np.full(n, 1.0 / n)
+    for _ in range(iters):
+        push = np.where(deg > 0, r / np.maximum(deg, 1.0), 0.0)
+        pulled = np.bincount(dst, weights=push[src], minlength=n)
+        dmass = r[deg == 0].sum() / n
+        r = (1.0 - alpha) / n + alpha * (pulled + dmass)
+    return r
 
 
 def execute_ref(graph: Graph, query) -> Result:

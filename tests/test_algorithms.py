@@ -120,6 +120,20 @@ def test_pagerank_sums_to_one_and_matches_numpy():
     np.testing.assert_allclose(got, r, rtol=1e-3, atol=1e-6)
 
 
+def test_pagerank_edge_list_oracle_matches_algorithm():
+    """`query.reference.pagerank_ref` (the oracle the chip smoke run checks
+    CALL algo.pagerank against) agrees with the algorithm, dangling
+    vertices included."""
+    from repro.query.reference import pagerank_ref
+    src, dst, _ = rand_digraph(seed=8)
+    src, dst = src[src % 7 != 0], dst[src % 7 != 0]     # dangling rows
+    g = GraphBuilder(N).add_edges("R", src, dst).build(fmt="ell")
+    pairs = np.unique(np.stack([src, dst], 1), axis=0)
+    want = pagerank_ref(pairs[:, 0], pairs[:, 1], N, iters=45)
+    got = np.asarray(alg.pagerank(g.relations["R"], iters=45))
+    np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-7)
+
+
 def test_wcc_matches_union_find():
     rng = np.random.default_rng(5)
     # a few disjoint clusters with random internal edges

@@ -255,6 +255,26 @@ def test_bitmap_kernel_interpret_matches_reference(name):
     np.testing.assert_array_equal(got, want)
 
 
+@pytest.mark.parametrize("slot_chunk,rows_tile", [(8, 8), (16, 16), (512, 8)])
+def test_bitmap_kernel_slot_chunks_match_reference(slot_chunk, rows_tile):
+    """A hub row spans many slot chunks and whole row tiles hold no edge:
+    the clamped chunk stream and the per-row slot counts must still give
+    the reference's words."""
+    from repro.kernels import bitmap_mxv
+    rng = np.random.default_rng(4)
+    n = 200
+    src = np.concatenate([rng.integers(40, n, 600), np.zeros(150, int),
+                          np.full(30, 7)])
+    dst = rng.integers(0, n, src.shape[0])
+    e = ELL.from_coo(src, dst, None, (n, n))     # rows 8..39 bar 7 are empty
+    assert e.max_deg > 4 * slot_chunk or slot_chunk >= e.max_deg
+    Xw = bitmap.pack(jnp.asarray(_bool_frontier(n, 70, seed=5, p=0.05)))
+    want = np.asarray(cops.ell_mxm_packed(e, Xw))
+    got = np.asarray(bitmap_mxv.ell_mxv_packed(
+        e, Xw, slot_chunk=slot_chunk, rows_tile=rows_tile, interpret=True))
+    np.testing.assert_array_equal(got, want)
+
+
 # -- algorithms ride the packed path bit-identically --------------------------
 def test_khop_bfs_wcc_packed_identical():
     from repro import algorithms as alg

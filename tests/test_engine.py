@@ -21,6 +21,21 @@ def test_create_and_query(tmp_path):
     assert res.rows == [(1, 2)]
 
 
+@pytest.mark.parametrize("write", ["CREATE (0)-[:KNOWS]->(5)",
+                                   "DELETE (0)-[:KNOWS]->(1)"])
+def test_bulk_loaded_graph_refuses_writes(tmp_path, write):
+    """A load_graph graph is served as loaded: a write must raise (and
+    reach no AOF) rather than be acknowledged and never read back."""
+    db = Database(data_dir=str(tmp_path))
+    db.load_graph("g", social_graph(n=64, seed=3))
+    before = db.query("g", "MATCH (a)-[:KNOWS]->(b) RETURN count(b)").rows
+    with pytest.raises(TypeError, match="bulk-loaded"):
+        db.query("g", write)
+    assert not any(p.is_file() and p.stat().st_size for p in tmp_path.iterdir())
+    assert db.query("g", "MATCH (a)-[:KNOWS]->(b) RETURN count(b)").rows \
+        == before
+
+
 def test_aof_replay_recovers_after_crash(tmp_path):
     db = Database(data_dir=str(tmp_path))
     db.query("g", "CREATE (:Person {id: 0}), (:Person {id: 1}), (:Person {id: 2})")
