@@ -53,6 +53,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.core.bsr import BSR
 from repro.core.ell import ELL
 
@@ -309,32 +310,38 @@ class DeltaMatrix:
             if self.pending == 0:
                 self._patch = (None, None)
             else:
-                n, m = self.shape
-                rows = self.touched_rows()
-                br, bc, bv = self.index.row_slice(rows)
-                k = br * m + bc
-                drop = _in_sorted(np.sort(self.minus_r * m + self.minus_c), k)
-                drop |= _in_sorted(np.sort(self.plus_r * m + self.plus_c), k)
-                er = np.concatenate([br[drop == False], self.plus_r])  # noqa: E712
-                ec = np.concatenate([bc[~drop], self.plus_c])
-                ev = np.concatenate([bv[~drop], self.plus_v])
-                er = np.searchsorted(rows, er)      # patch-local row ids
-                t, tp = len(rows), 8
-                while tp < t:
-                    tp *= 2
-                md = int(np.bincount(er, minlength=1).max()) if len(er) else 1
-                pad = 8
-                while pad < md:
-                    pad *= 2
-                scatter = np.full(tp, n, dtype=np.int32)
-                scatter[:t] = rows
-                # the cache outlives any trace that triggers the build (e.g.
-                # sssp's while_loop body) — arrays must be concrete, never
-                # trace-bound tracers (same rule as GBMatrix.T)
-                with jax.ensure_compile_time_eval():
-                    self._patch = (ELL.from_coo(er, ec, ev, (tp, m),
-                                                pad_deg_to=pad),
-                                   jnp.asarray(scatter))
+                with tracing.span("repro.delta.patch"):
+                    n, m = self.shape
+                    rows = self.touched_rows()
+                    br, bc, bv = self.index.row_slice(rows)
+                    k = br * m + bc
+                    drop = _in_sorted(
+                        np.sort(self.minus_r * m + self.minus_c), k)
+                    drop |= _in_sorted(
+                        np.sort(self.plus_r * m + self.plus_c), k)
+                    er = np.concatenate([br[drop == False],  # noqa: E712
+                                         self.plus_r])
+                    ec = np.concatenate([bc[~drop], self.plus_c])
+                    ev = np.concatenate([bv[~drop], self.plus_v])
+                    er = np.searchsorted(rows, er)      # patch-local row ids
+                    t, tp = len(rows), 8
+                    while tp < t:
+                        tp *= 2
+                    md = (int(np.bincount(er, minlength=1).max())
+                          if len(er) else 1)
+                    pad = 8
+                    while pad < md:
+                        pad *= 2
+                    scatter = np.full(tp, n, dtype=np.int32)
+                    scatter[:t] = rows
+                    # the cache outlives any trace that triggers the build
+                    # (e.g. sssp's while_loop body) — arrays must be
+                    # concrete, never trace-bound tracers (same rule as
+                    # GBMatrix.T)
+                    with jax.ensure_compile_time_eval():
+                        self._patch = (ELL.from_coo(er, ec, ev, (tp, m),
+                                                    pad_deg_to=pad),
+                                       jnp.asarray(scatter))
         return self._patch
 
     def effective_coo(self):

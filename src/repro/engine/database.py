@@ -29,6 +29,7 @@ from typing import Dict, Optional, Tuple
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.core import grb
 from repro.core.delta import DeltaMatrix, needs_compaction
 from repro.core.ell import ELL
@@ -193,6 +194,7 @@ class MutableGraph:
             return g
         idx, prev = served
         ops = self._oplog[idx:]
+        tracing.add("repro.delta.ops", len(ops))
         by_rel: Dict[str, list] = {}
         for rel, kind, s, d, w in ops:
             by_rel.setdefault(rel, []).append((kind, s, d, w))
@@ -228,14 +230,16 @@ class MutableGraph:
             fwd: DeltaMatrix = prev_rel.A.store
             twin = prev_rel.A.T.store
         if ops:
-            fwd = fwd.apply_ops([(k, s, d, w) for k, s, d, w in ops],
-                                grow_to=(n, n))
-            twin = twin.apply_ops([(k, d, s, w) for k, s, d, w in ops],
-                                  grow_to=(n, n))
+            with tracing.span("repro.delta.apply_ops"):
+                fwd = fwd.apply_ops([(k, s, d, w) for k, s, d, w in ops],
+                                    grow_to=(n, n))
+                twin = twin.apply_ops([(k, d, s, w) for k, s, d, w in ops],
+                                      grow_to=(n, n))
         elif fwd.shape[0] < n:
             fwd, twin = fwd.resize((n, n)), twin.resize((n, n))
         if needs_compaction(fwd):
-            fwd, twin = fwd.compact(), twin.compact()
+            with tracing.span("repro.delta.compact"):
+                fwd, twin = fwd.compact(), twin.compact()
             self.compactions += 1
         h = grb.GBMatrix(fwd, name=name)
         h.link_transpose(grb.GBMatrix(twin, name=name + "^T"))
@@ -342,11 +346,13 @@ class Database:
                             f"and takes no writes; build it through CREATE "
                             f"to serve writes on the delta path")
         if isinstance(q, A.CreateQuery):
-            self._append_aof(name, text)
-            return self._apply_create(name, q)
+            with tracing.span("repro.write"):
+                self._append_aof(name, text)
+                return self._apply_create(name, q)
         if isinstance(q, A.DeleteQuery):
-            self._append_aof(name, text)
-            return self._apply_delete(name, q)
+            with tracing.span("repro.write"):
+                self._append_aof(name, text)
+                return self._apply_delete(name, q)
         return self.context(name, impl=impl, mesh=mesh).run(q)
 
     def context(self, name: str, impl: str = "auto",

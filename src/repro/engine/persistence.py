@@ -23,6 +23,7 @@ from typing import Iterator, Optional, Tuple
 
 import numpy as np
 
+from repro import tracing
 from repro.graph.graph import Graph, GraphBuilder
 
 
@@ -36,8 +37,9 @@ def append_aof(path: str, text: str) -> None:
     Redis appendfsync-always durability point)."""
     with open(path, "a") as f:
         f.write(text.replace("\n", " ") + "\n")
-        f.flush()
-        os.fsync(f.fileno())
+        with tracing.span("repro.aof.fsync"):
+            f.flush()
+            os.fsync(f.fileno())
 
 
 def iter_aof(data_dir: str) -> Iterator[Tuple[str, str]]:

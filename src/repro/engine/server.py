@@ -33,9 +33,13 @@ Graph, and every batch serves the freshest snapshot-consistent freeze (the
 delta layer makes that a functional catch-up, not a rebuild). A plain frozen
 `Graph` is served as-is.
 
-Measured by `benchmarks/bench_throughput.py` (Poisson open-loop arrivals:
-batched vs one-query-at-a-time queries/sec at matching p99) and pinned by
-`tests/test_server.py` (batched ≡ solo differential grid).
+Each step is a `repro.tracing` span (`repro.submit`, `repro.pump` and
+within it `repro.admit`, `repro.refresh`, `repro.launch`, `repro.fetch`,
+`repro.project`; `repro.mask` inside the launch), and `stats` carries
+their totals. The batch id in the launch, fetch and project spans is the
+launch sequence number, which each `Submitted` records. Measured on one TPU v5e at Graph500 scale 15 by the
+benchmark in `bench/` (PERF.md: knee 2,200 q/s at `MAX_WIDTH` 512) and
+pinned by `tests/test_server.py` (batched ≡ solo differential grid).
 """
 from __future__ import annotations
 
@@ -45,6 +49,7 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
+from repro import tracing
 from repro.core import grb
 from repro.graph.graph import Graph
 from repro.query.executor import (ExecutionContext, Result, empty_result,
@@ -74,6 +79,7 @@ class Submitted:
     width: int                          # admission width: seed columns asked
     result: Optional[Result] = None
     wait_s: float = 0.0                 # queue wait: submit -> batch launch
+    batch: int = -1                     # launch sequence number of its batch
     latency_s: float = 0.0              # submit -> result materialized
 
 
@@ -88,6 +94,7 @@ class _Batch:
     B: Optional[object]                 # (n, F) device frontier, or None
     error: Optional[Exception]
     solo: bool                          # unseeded singleton (stats bucket)
+    seq: int                            # launch sequence number
 
 
 def _error_result(e: Exception) -> Result:
@@ -125,8 +132,9 @@ class QueryServer:
         self._inflight: Optional[_Batch] = None
         self._ctx: Optional[ExecutionContext] = None
         self._next_id = 0
+        self._launches = 0                  # batches launched: the batch id
         self.log: List[Submitted] = []      # completed queries, in order
-        self.stats = {
+        self._stats = {
             "queries": 0, "batches": 0, "solo": 0, "errors": 0,
             "batched_width_total": 0, "batch_width_max": 0,
             "plan_cache_hits": 0, "plan_cache_misses": 0,
@@ -141,6 +149,13 @@ class QueryServer:
         self._xfer0 = grb.host_transfers()
         self._refresh()                     # fail fast on a bad source
 
+    @property
+    def stats(self) -> Dict[str, tracing.Number]:
+        """The server's cumulative counters, with the process's span,
+        counter and compile totals (`tracing.totals()`): one flat dict
+        of numbers, a snapshot that later serving leaves unchanged."""
+        return {**self._stats, **tracing.totals()}
+
     # -- submission -----------------------------------------------------------
     def submit(self, text: str, seeds=None,
                arrival_s: Optional[float] = None) -> int:
@@ -150,19 +165,20 @@ class QueryServer:
         ``arrival_s`` (perf_counter clock) backdates arrival for open-loop
         load replay; it defaults to now. Parse/plan errors raise here, to
         the submitter — they never reach the queue."""
-        p, sig = self._plans.get(text)
-        self.stats["plan_cache_hits"] = self._plans.hits
-        self.stats["plan_cache_misses"] = self._plans.misses
-        self.stats["plan_cache_hit_rate"] = self._plans.hit_rate
-        if seeds is not None:
-            p = dataclasses.replace(p, seeds=[int(s) for s in seeds])
-        s = Submitted(self._next_id, p, sig,
-                      arrival_s if arrival_s is not None
-                      else time.perf_counter(),
-                      len(p.seeds) if p.seeds is not None else 0)
-        self._next_id += 1
-        self._queue.append(s)
-        return s.qid
+        with tracing.span("repro.submit", qid=self._next_id):
+            p, sig = self._plans.get(text)
+            self._stats["plan_cache_hits"] = self._plans.hits
+            self._stats["plan_cache_misses"] = self._plans.misses
+            self._stats["plan_cache_hit_rate"] = self._plans.hit_rate
+            if seeds is not None:
+                p = dataclasses.replace(p, seeds=[int(s) for s in seeds])
+            s = Submitted(self._next_id, p, sig,
+                          arrival_s if arrival_s is not None
+                          else time.perf_counter(),
+                          len(p.seeds) if p.seeds is not None else 0)
+            self._next_id += 1
+            self._queue.append(s)
+            return s.qid
 
     @property
     def pending(self) -> int:
@@ -178,24 +194,33 @@ class QueryServer:
         new sweep runs on the device. Returns the queries completed this
         tick (usually the previous batch). Never raises per-query errors —
         they come back as error Results."""
-        out: Dict[int, Result] = {}
-        nxt: Optional[_Batch] = None
-        chunk = self._next_chunk()
-        if chunk:
-            try:
-                ctx = self._refresh()
-                nxt = self._launch(ctx, chunk)
-            except Exception as e:            # snapshot/refresh failure
-                t0 = time.perf_counter()
+        with tracing.span("repro.pump", cpu=True):
+            out: Dict[int, Result] = {}
+            nxt: Optional[_Batch] = None
+            with tracing.span("repro.admit"):
+                chunk = self._next_chunk()
+            if chunk:
+                seq = self._launches
+                self._launches += 1
                 for m in chunk:
-                    m.wait_s = t0 - m.t_submit
-                self.stats["queries"] += len(chunk)
-                nxt = _Batch(chunk, [], self._ctx, [], None, e,
-                             chunk[0].plan.seeds is None)
-        if self._inflight is not None:
-            self._finish(self._inflight, out)
-        self._inflight = nxt
-        return out
+                    m.batch = seq
+                try:
+                    with tracing.span("repro.refresh"):
+                        ctx = self._refresh()
+                    with tracing.span("repro.launch", batch=seq,
+                                      width=sum(m.width for m in chunk)):
+                        nxt = self._launch(ctx, chunk, seq)
+                except Exception as e:        # snapshot/refresh failure
+                    t0 = time.perf_counter()
+                    for m in chunk:
+                        m.wait_s = t0 - m.t_submit
+                    self._stats["queries"] += len(chunk)
+                    nxt = _Batch(chunk, [], self._ctx, [], None, e,
+                                 chunk[0].plan.seeds is None, seq)
+            if self._inflight is not None:
+                self._finish(self._inflight, out)
+            self._inflight = nxt
+            return out
 
     def flush(self) -> Dict[int, Result]:
         """Execute everything queued (and in flight); the queue always
@@ -259,18 +284,20 @@ class QueryServer:
         self._queue = rest
         return take
 
-    def _launch(self, ctx: ExecutionContext,
-                members: List[Submitted]) -> _Batch:
+    def _launch(self, ctx: ExecutionContext, members: List[Submitted],
+                seq: int) -> _Batch:
         """Resolve the chunk's seeds and enqueue its device sweep. Member-
         specific failures (bad seed ids) drop only that member; chunk-level
         failures (unknown label/relation — shared by construction, the
         members are signature-equal) mark the batch for finish() to
-        isolate. Does NOT block on the device."""
+        isolate. Nothing here waits for the sweep it enqueues; reading a
+        label mask back, and dispatching while the device's queue is full,
+        wait for the sweep before it."""
         t0 = time.perf_counter()
         solo = members[0].plan.seeds is None
         for m in members:
             m.wait_s = t0 - m.t_submit
-        b = _Batch(members, [], ctx, [], None, None, solo)
+        b = _Batch(members, [], ctx, [], None, None, solo, seq)
         p0 = members[0].plan
         try:
             src_mask = ctx.node_mask(p0.src_label,
@@ -306,21 +333,21 @@ class QueryServer:
             except Exception as e:
                 b.error = e
         # serving metrics (lanes are counted at launch, where padding is)
-        self.stats["queries"] += len(members)
+        self._stats["queries"] += len(members)
         if solo:
-            self.stats["solo"] += 1
+            self._stats["solo"] += 1
         else:
-            self.stats["batches"] += 1
-            self.stats["batched_width_total"] += width
-            self.stats["batch_width_max"] = max(
-                self.stats["batch_width_max"], width)
+            self._stats["batches"] += 1
+            self._stats["batched_width_total"] += width
+            self._stats["batch_width_max"] = max(
+                self._stats["batch_width_max"], width)
             if width and b.error is None:   # lanes of sweeps actually run
-                self.stats["pack_lanes"] += width
-                self.stats["pack_slots"] += (_aligned(width) if self.align
-                                             else width)
-                self.stats["pack_ratio"] = (self.stats["pack_lanes"]
-                                            / self.stats["pack_slots"])
-        self.stats["queue_wait_s_total"] += sum(m.wait_s for m in members)
+                self._stats["pack_lanes"] += width
+                self._stats["pack_slots"] += (_aligned(width) if self.align
+                                              else width)
+                self._stats["pack_ratio"] = (self._stats["pack_lanes"]
+                                             / self._stats["pack_slots"])
+        self._stats["queue_wait_s_total"] += sum(m.wait_s for m in members)
         return b
 
     def _finish(self, b: _Batch, out: Dict[int, Result]) -> None:
@@ -337,17 +364,21 @@ class QueryServer:
                 except Exception as e:
                     m.result = _error_result(e)
         elif b.B is not None:
-            Bn = np.asarray(b.B)
-            off = 0
-            for m, seeds in zip(b.members, b.seed_lists):
-                w = len(seeds)
-                try:
-                    m.result = (b.ctx.project(m.plan, seeds,
-                                              Bn[:, off:off + w])
-                                if w else empty_result(m.plan))
-                except Exception as e:
-                    m.result = _error_result(e)
-                off += w
+            with tracing.span("repro.fetch", batch=b.seq):
+                Bn = np.asarray(b.B)
+            tracing.add("repro.fetch.bytes", Bn.nbytes)
+            with tracing.span("repro.project", batch=b.seq):
+                off = 0
+                for m, seeds in zip(b.members, b.seed_lists):
+                    w = len(seeds)
+                    try:
+                        m.result = (b.ctx.project(m.plan, seeds,
+                                                  Bn[:, off:off + w])
+                                    if w else empty_result(m.plan))
+                    except Exception as e:
+                        m.result = _error_result(e)
+                    off += w
+            tracing.add("repro.project.reads", len(b.members))
         else:                               # every member resolved empty
             for m in b.members:
                 m.result = empty_result(m.plan)
@@ -355,7 +386,7 @@ class QueryServer:
         for m in b.members + b.failed:
             m.latency_s = t1 - m.t_submit
             if m.result.error is not None:
-                self.stats["errors"] += 1
+                self._stats["errors"] += 1
             out[m.qid] = m.result
             self.log.append(m)
-        self.stats["host_transfers"] = grb.host_transfers() - self._xfer0
+        self._stats["host_transfers"] = grb.host_transfers() - self._xfer0

@@ -42,6 +42,7 @@ from typing import Dict, List, Optional
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.core import bitmap as _bitmap, grb, semiring as S
 from repro.core.grb import Descriptor
 from repro.graph.graph import Graph
@@ -346,9 +347,13 @@ class ExecutionContext:
         return m
 
     def node_mask(self, label, preds=None) -> np.ndarray:
-        """bool (n,): vertices carrying `label` and passing all predicates."""
+        """bool (n,): vertices carrying `label` and passing all predicates.
+        The label's mask lives on the device; its read (span `repro.mask`)
+        waits for the device work queued ahead of it, such as a sweep in
+        flight."""
         n = self.graph.n
-        m = np.asarray(self.graph.label_mask(label))
+        with tracing.span("repro.mask"):
+            m = np.asarray(self.graph.label_mask(label))
         for p in preds or []:
             m = m & eval_pred(self.graph, p, n)
         return m
