@@ -33,7 +33,6 @@ bit-scatter lowering. Gather-to-host conversions are counted via
 """
 from __future__ import annotations
 
-import dataclasses
 from typing import Optional, Tuple
 
 import jax
@@ -41,7 +40,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import bitmap, xfer
-from repro.core.ell import ELL
+from repro.core.ell import ELL, HostCount, checked_nnz
 
 Array = jnp.ndarray
 
@@ -90,24 +89,32 @@ def auto_bitadj_ok(rows, cols, vals, shape) -> bool:
 
 
 @jax.tree_util.register_pytree_node_class
-@dataclasses.dataclass
 class BitELL:
-    shape: Tuple[int, int]
-    tiles: Array        # (P, S, 32) uint32 bit-tiles (see module doc)
-    cols: Array         # (P, S) i32 column-tile per slot; sentinel = n_ctiles
-    nnz: int
-    # cached ELL materialization (the weighted/ewise/delta fallback target);
-    # host-side cache like GBMatrix._T, never part of the traced pytree
-    _ell: Optional[ELL] = dataclasses.field(
-        default=None, repr=False, compare=False)
+    """``nnz`` is an exact host int, read without touching the device; the
+    pytree's static data is the shape alone (see `core.ell.HostCount`)."""
+
+    def __init__(self, shape: Tuple[int, int], tiles: Array, cols: Array,
+                 nnz: Optional[int]):
+        self.shape = shape
+        self.tiles = tiles  # (P, S, 32) uint32 bit-tiles (see module doc)
+        self.cols = cols    # (P, S) i32 column-tile per slot; sentinel = n_ctiles
+        self._nnz = None if nnz is None else int(nnz)
+        # cached ELL materialization (the weighted/ewise/delta fallback
+        # target); host-side cache like GBMatrix._T, never part of the
+        # traced pytree
+        self._ell: Optional[ELL] = None
+
+    @property
+    def nnz(self) -> int:
+        return checked_nnz("BitELL", self._nnz)
 
     def tree_flatten(self):
-        return (self.tiles, self.cols), (self.shape, self.nnz)
+        return (self.tiles, self.cols), (self.shape, HostCount(self._nnz))
 
     @classmethod
     def tree_unflatten(cls, aux, children):
-        shape, nnz = aux
-        return cls(shape, *children, nnz=nnz)
+        shape, count = aux
+        return cls(shape, *children, nnz=count.rebuilt(children))
 
     # -- geometry ------------------------------------------------------------
     @property
@@ -225,7 +232,7 @@ class BitELL:
 
     def __repr__(self) -> str:
         n, k = self.shape
-        return (f"BitELL {n}x{k} nnz={self.nnz} panels={self.n_panels} "
+        return (f"BitELL {n}x{k} nnz={self._nnz} panels={self.n_panels} "
                 f"slots={self.n_slots} payload={self.payload_bytes}B")
 
 

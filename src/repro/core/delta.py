@@ -303,9 +303,12 @@ class DeltaMatrix:
         matrix size; ``rows`` maps patch row -> matrix row, padded with the
         out-of-bounds index n so consumers scatter the patch product with
         ``.at[rows].set(..., mode="drop")``. Both the row count and the ELL
-        width are power-of-two bucketed: each distinct shape is a fresh XLA
-        compile on the serving path, bucketing caps a live-write stream at
-        O(log^2 n) patch compilations. (None, None) if no deltas pending."""
+        width are power-of-two bucketed, and they are all of the patch that
+        keys a compiled program: the ELL pytree leaves its exact count out
+        of its static data (`core.ell.HostCount`). A sweep's compile key is
+        (row bucket, width bucket, frontier width), so a live-write stream
+        builds O(log^2 n) patch programs per frontier width, not one per
+        write. (None, None) if no deltas pending."""
         if self._patch is None:
             if self.pending == 0:
                 self._patch = (None, None)

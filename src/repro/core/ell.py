@@ -11,30 +11,82 @@ CSR/bitmap/hypersparse switching: build BSR, check fill_ratio, fall back here.
 """
 from __future__ import annotations
 
-import dataclasses
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 
+class HostCount:
+    """A storage pytree's exact stored-entry count, kept in its static data
+    but left out of the treedef's equality and hash: no compiled program
+    reads the count, so it must not key one (a live delta patch keeps its
+    shape while its count moves with every write). Only a node rebuilt
+    from concrete arrays gets it back (`rebuilt`)."""
+    __slots__ = ("value",)
+
+    def __init__(self, value: Optional[int]):
+        self.value = value
+
+    def __eq__(self, other) -> bool:
+        return isinstance(other, HostCount)
+
+    def __hash__(self) -> int:
+        return hash(HostCount)
+
+    def __repr__(self) -> str:
+        return "HostCount()"
+
+    def rebuilt(self, children) -> Optional[int]:
+        """The count of a node rebuilt from ``children``: exact after a
+        tree_map or device_put of concrete arrays; None where any child is
+        a tracer, because one executable serves every count and the one
+        met inside its trace would be that of the call that traced it."""
+        if any(isinstance(c, jax.core.Tracer) for c in children):
+            return None
+        return self.value
+
+
+def checked_nnz(kind: str, nnz: Optional[int]) -> int:
+    if nnz is None:
+        raise ValueError(
+            f"{kind}.nnz is unknown inside a trace: the compiled program "
+            f"serves every count, so the pytree leaves it out; read it on "
+            f"the host, outside jit")
+    return nnz
+
+
 @jax.tree_util.register_pytree_node_class
-@dataclasses.dataclass
 class ELL:
-    shape: Tuple[int, int]
-    indices: jnp.ndarray  # (n, max_deg) i32 neighbor ids, padded with 0
-    mask: jnp.ndarray     # (n, max_deg) bool validity
-    values: jnp.ndarray   # (n, max_deg) f32 edge weights (1.0 structural)
-    nnz: int
+    """``nnz`` is an exact host int, read without touching the device; the
+    pytree's static data is the shape alone (see `HostCount`)."""
+
+    def __init__(self, shape: Tuple[int, int], indices: jnp.ndarray,
+                 mask: jnp.ndarray, values: jnp.ndarray,
+                 nnz: Optional[int]):
+        self.shape = shape
+        self.indices = indices  # (n, max_deg) i32 neighbor ids, padded with 0
+        self.mask = mask        # (n, max_deg) bool validity
+        self.values = values    # (n, max_deg) f32 edge weights (1.0 structural)
+        self._nnz = None if nnz is None else int(nnz)
+
+    @property
+    def nnz(self) -> int:
+        return checked_nnz("ELL", self._nnz)
 
     def tree_flatten(self):
-        return (self.indices, self.mask, self.values), (self.shape, self.nnz)
+        return ((self.indices, self.mask, self.values),
+                (self.shape, HostCount(self._nnz)))
 
     @classmethod
     def tree_unflatten(cls, aux, children):
-        shape, nnz = aux
-        return cls(shape, *children, nnz=nnz)
+        shape, count = aux
+        return cls(shape, *children, nnz=count.rebuilt(children))
+
+    def __repr__(self) -> str:
+        n, m = self.shape
+        return f"ELL {n}x{m} max_deg={self.max_deg} nnz={self._nnz}"
 
     @property
     def max_deg(self) -> int:

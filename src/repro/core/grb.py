@@ -89,6 +89,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import weakref
 from typing import Callable, Optional, Union
 
 import jax
@@ -288,7 +289,8 @@ class GBMatrix:
     pytrees / jnp arrays) is what flows through jit. Inside traced code,
     close over the handle — do not pass it as a traced argument.
     """
-    __slots__ = ("store", "fmt", "impl", "auto", "name", "_T", "_sharded")
+    __slots__ = ("store", "fmt", "impl", "auto", "name", "_t", "_sharded",
+                 "__weakref__")
 
     def __init__(self, store: Storage, impl: str = "auto", name: str = ""):
         if isinstance(store, GBMatrix):
@@ -305,7 +307,7 @@ class GBMatrix:
         self.impl = _resolve_impl(impl, self.fmt,
                                   store if isinstance(store, BSR) else None)
         self.name = name
-        self._T: Optional["GBMatrix"] = None
+        self._t = None      # the linked transpose (see link_transpose)
         # mesh -> distributed twin, filled by grb.distribute (like the _T
         # cache: serving contexts re-resolve per query and must not re-pad
         # + re-device_put the whole graph each time)
@@ -363,6 +365,11 @@ class GBMatrix:
 
     # -- transpose -----------------------------------------------------------
     @property
+    def _T(self) -> Optional["GBMatrix"]:
+        t = self._t
+        return t() if isinstance(t, weakref.ref) else t
+
+    @property
     def T(self) -> "GBMatrix":
         """Stored transpose, built once and cached; ``A.T.T is A``."""
         if self._T is None:
@@ -384,9 +391,13 @@ class GBMatrix:
 
     def link_transpose(self, other: "GBMatrix") -> "GBMatrix":
         """Install an explicitly-built transpose (RedisGraph maintains these
-        per relation) so ``.T`` never rebuilds it."""
-        self._T = other
-        other._T = self
+        per relation) so ``.T`` never rebuilds it. ``self`` holds the twin
+        and the twin refers back weakly: a strong pair would be a reference
+        cycle, freed (with the device arrays of its storage, such as a
+        frozen snapshot's delta patches) only when Python's cyclic
+        collector runs, not when the last handle goes."""
+        self._t = other
+        other._t = weakref.ref(self)
         return self
 
     # -- policy --------------------------------------------------------------

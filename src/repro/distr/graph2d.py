@@ -143,14 +143,15 @@ def mxm_2d(mesh: Mesh, sr: S.Semiring, transposed: bool = False,
     if not transposed and packed:
         def body(idx_l, msk_l, val_l, xw_l):
             xw = jax.lax.all_gather(xw_l, "data", axis=0, tiled=True)
+            # a shard's count is not known inside the trace: none
             local = ELL(shape=(idx_l.shape[0], xw.shape[0]), indices=idx_l,
-                        mask=msk_l, values=val_l, nnz=0)
+                        mask=msk_l, values=val_l, nnz=None)
             return _ell_words(local, xw)
     elif not transposed:
         def body(idx_l, msk_l, val_l, x_l):
             x = jax.lax.all_gather(x_l, "data", axis=0, tiled=True)
             local = ELL(shape=(idx_l.shape[0], x.shape[0]), indices=idx_l,
-                        mask=msk_l, values=val_l, nnz=0)
+                        mask=msk_l, values=val_l, nnz=None)
             return _core_ops.ell_mxm(local, x, sr)
     elif packed:
         from repro.core import bitmap

@@ -108,6 +108,40 @@ def test_builder_links_explicit_transpose():
                                np.asarray(A.to_dense()).T, rtol=1e-6)
 
 
+@pytest.mark.parametrize("fmt", ["ell", "delta"])
+def test_linked_transpose_pair_freed_by_refcount(fmt):
+    """A linked pair is no reference cycle: dropping the handle that linked
+    it frees both, storage included, with the cyclic collector off. A twin
+    that outlives its handle builds a transpose of its own."""
+    import gc
+    import weakref
+    from repro.core.delta import DeltaMatrix
+    r, c, v, D, _, _, _ = _case(seed=11)
+    A = _handle("ell", r, c, v, D)
+    At = grb.GBMatrix(ELL.from_coo(c, r, v, (M, N)))
+    if fmt == "delta":
+        ops_ = [("add", 0, 1, 2.0), ("del", int(r[0]), int(c[0]), 0.0)]
+        A = grb.GBMatrix(DeltaMatrix.wrap(A.store).apply_ops(ops_))
+        At = grb.GBMatrix(DeltaMatrix.wrap(At.store).apply_ops(
+            [(k, j, i, w) for k, i, j, w in ops_]))
+        assert A.store.patch()[0] is not None
+    A.link_transpose(At)
+    assert A.T is At and At.T is A
+    twin, store = weakref.ref(At), weakref.ref(At.store)
+    del At
+    gc.disable()
+    try:
+        del A
+        assert twin() is None and store() is None
+    finally:
+        gc.enable()
+    B = _handle("ell", r, c, v, D)
+    Bt = B.T
+    want = np.asarray(B.to_dense())
+    del B
+    np.testing.assert_allclose(np.asarray(Bt.T.to_dense()), want, rtol=1e-6)
+
+
 def test_handle_introspection_and_policy():
     r, c, v, D, _, _, _ = _case(seed=9)
     for fmt, expect_nvals in (("bsr", len(r)), ("ell", len(r)),
